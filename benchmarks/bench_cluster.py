@@ -68,6 +68,7 @@ from repro.serving.batching import (BatchedServingEngine,  # noqa: E402
 from repro.serving.cluster import (ClusterFrontend, QosAutopilot,  # noqa: E402
                                    ReplicaPool, ROUTERS)
 from repro.serving.frontend import ServingFrontend  # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 
 RESULTS = os.path.join(os.path.dirname(__file__), "..", "results", "bench")
 
@@ -462,6 +463,7 @@ def main():
                     help="(--disagg) dump the captured pool's "
                          "cluster+replica metrics snapshot as JSON")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.smoke:
         args.replicas, args.requests, args.max_new = "2", 10, 3

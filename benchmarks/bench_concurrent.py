@@ -43,6 +43,7 @@ from repro.serving.api import GenerationRequest, SamplingParams
 from repro.serving.batching import (BatchedServingEngine, RequestQueue,
                                     parse_prefill_budget)
 from repro.serving.frontend import ServingFrontend
+from repro.launch.compile_cache import enable_compile_cache
 
 RESULTS = os.path.join(os.path.dirname(__file__), "..", "results", "bench")
 
@@ -171,6 +172,7 @@ def main():
                     help="tokens a victim streams before it is cancelled")
     ap.add_argument("--out", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = reduced(get_config(args.arch))
     bundle = build(cfg)

@@ -226,6 +226,10 @@ def run_obs_overhead(batch: int = 8, max_new: int = 24, budget: int = 4,
 if __name__ == "__main__":
     import argparse
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--grouped", action="store_true",
                     help="real-engine dense-vs-grouped expert execution A/B")

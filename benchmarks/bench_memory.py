@@ -101,6 +101,10 @@ def smoke() -> None:
 
 if __name__ == "__main__":
     import argparse
+
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="tiny real-engine run asserting the expert-HBM "

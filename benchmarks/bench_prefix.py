@@ -57,6 +57,7 @@ from repro.serving.batching import (BatchedServingEngine,  # noqa: E402
 from repro.serving.cluster import (ClusterFrontend,  # noqa: E402
                                    ReplicaPool)
 from repro.serving.frontend import ServingFrontend  # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 
 RESULTS = os.path.join(os.path.dirname(__file__), "..", "results", "bench")
 
@@ -238,6 +239,7 @@ def main():
                          "expert-HBM bound")
     ap.add_argument("--out", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.smoke:
         args.requests, args.max_new = 12, 2

@@ -52,6 +52,7 @@ from repro.configs.base import get_config, reduced  # noqa: E402
 from repro.core.qos import TBTLedger, percentile_report
 from repro.models.model import build
 from repro.serving.batching import BatchedServingEngine
+from repro.launch.compile_cache import enable_compile_cache
 
 RESULTS = os.path.join(os.path.dirname(__file__), "..", "results", "bench")
 
@@ -152,6 +153,7 @@ def main():
                          "stall bound, exit nonzero on violation")
     ap.add_argument("--out", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.smoke:
         args.budgets, args.decoders, args.n_long = "2", 1, 1

@@ -25,5 +25,8 @@ def run(models=("mixtral-8x7b", "qwen3-30b-a3b"), quick=False):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     for name, us, derived in run():
         print(f"{name},{us:.1f},{derived}")

@@ -195,5 +195,8 @@ def run(quick=False):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     for name, us, derived in run():
         print(f"{name},{us:.1f},{derived}")

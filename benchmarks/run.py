@@ -23,6 +23,9 @@ def main(argv=None) -> None:
     ap.add_argument("--only", default=None,
                     help="comma-separated bench names")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     from benchmarks import (bench_latency, bench_memory, bench_predictor,
                             bench_tail, bench_throughput, roofline)

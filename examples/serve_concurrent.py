@@ -32,6 +32,7 @@ import numpy as np
 from repro.configs.base import get_config, reduced
 from repro.core.qos import AdmissionController, LatencyModel, percentile_report
 from repro.data.pipeline import PromptWorkload, squad_like
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import build
 from repro.serving.api import GenerationRequest, SamplingParams
 from repro.serving.batching import (BatchedServingEngine, RequestQueue,
@@ -59,6 +60,7 @@ def main():
                     help="tiny fast run for CI: small workload, chunked "
                          "prefill, asserts parity + cancellation safety")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.smoke:
         args.requests, args.max_new = 3, 3

@@ -163,8 +163,7 @@ def _check_donation(spec: KernelSpec) -> List[AuditFinding]:
         return [AuditFinding(
             "jaxpr-donation", spec.name, f"could not lower to check donation: {e}"
         )]
-    # donation lowers to `tf.aliasing_output` (jax<=0.4.x CPU) or
-    # `jax.buffer_donor` on newer versions
+    # donation lowers to `tf.aliasing_output` or `jax.buffer_donor`
     if "aliasing_output" not in text and "buffer_donor" not in text:
         return [AuditFinding(
             "jaxpr-donation", spec.name,

@@ -29,8 +29,10 @@ from __future__ import annotations
 import collections
 import dataclasses
 import functools
+import os
 import time
-from typing import Dict, Iterable, List, Set, Tuple
+from concurrent.futures import ThreadPoolExecutor
+from typing import Dict, Iterable, List, Optional, Set, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -58,6 +60,33 @@ class HostExpertStore:
                              np.asarray(layer_moe_params["w3"][l, e]),
                              np.asarray(layer_moe_params["w2"][l, e]))
         return HostExpertStore(w)
+
+    @staticmethod
+    def from_seed(n_layers: int, n_experts: int, d_model: int,
+                  d_expert: int, *, seed: int = 0, dtype=jnp.bfloat16,
+                  workers: Optional[int] = None) -> "HostExpertStore":
+        """Random routed-expert weights made on the host: no expert stack
+        is ever built on the device. Expert (l, e) draws from its own
+        generator, seeded by (seed, l, e), so the store does not depend on
+        the number of worker threads (numpy releases the GIL while it
+        fills arrays). Values are uniform with the fan-in-scaled standard
+        deviation of ``models.moe_layer.moe_params`` (d**-0.5 for w1/w3,
+        d_expert**-0.5 for w2)."""
+        def draw(rng, shape, fan_in):
+            a = rng.random(shape, dtype=np.float32)
+            a -= 0.5
+            a *= 2.0 * np.sqrt(3.0 / fan_in)  # U(-sqrt(3/n), sqrt(3/n))
+            return a.astype(dtype)
+
+        def one(key: ExpertKey):
+            rng = np.random.default_rng([seed, *key])
+            return key, (draw(rng, (d_model, d_expert), d_model),
+                         draw(rng, (d_model, d_expert), d_model),
+                         draw(rng, (d_expert, d_model), d_expert))
+
+        keys = [(l, e) for l in range(n_layers) for e in range(n_experts)]
+        with ThreadPoolExecutor(workers or os.cpu_count()) as ex:
+            return HostExpertStore(dict(ex.map(one, keys)))
 
     def get(self, key: ExpertKey):
         return self.weights[key]

@@ -24,6 +24,12 @@ convention.
 Grid: (E, f // block_f); the hidden dim is tiled so each expert's working set
 fits VMEM regardless of d_expert (SwiGLU is computed per f-tile and
 down-projected immediately: out += (silu(x@w1_j) * (x@w3_j)) @ w2_j).
+
+The default tile, ``DEFAULT_BLOCK_F = 256``, is the largest that fits a TPU
+v5e's default 16 MiB of scoped VMEM at mixtral-8x7b widths (d=4096,
+f=14336): at 512 the three double-buffered 4 MiB weight tiles alone come to
+24 MiB and the chip's compiler refuses the kernel
+(tests/test_tpu_compile.py compiles it for a described v5e).
 """
 from __future__ import annotations
 
@@ -49,17 +55,34 @@ def _kernel(x_ref, w1_ref, w3_ref, w2_ref, o_ref):
                         preferred_element_type=jnp.float32)
 
 
+DEFAULT_BLOCK_F = 256
+LANES = 128
+
+
+def f_tile(f: int, block_f: int = DEFAULT_BLOCK_F) -> int:
+    """Hidden-dim tile for d_expert ``f``: the full dim when it fits one
+    block (``f <= max(block_f, 128)``), else the largest multiple of 128
+    lanes that is at most ``block_f`` and divides ``f``. A tile the TPU
+    cannot lay out is an error, not a silent fallback."""
+    if f <= max(block_f, LANES):
+        return f
+    bf = max(LANES, block_f - block_f % LANES)
+    while f % bf:
+        bf -= LANES
+        if bf < LANES:
+            raise ValueError(f"d_expert={f} has no tile of a multiple of "
+                             f"{LANES} lanes <= block_f={block_f}")
+    return bf
+
+
 @functools.partial(jax.jit, static_argnames=("block_f", "interpret"))
 def expert_ffn(x: jax.Array, w1: jax.Array, w3: jax.Array, w2: jax.Array,
-               *, block_f: int = 512, interpret: bool = False) -> jax.Array:
+               *, block_f: int = DEFAULT_BLOCK_F,
+               interpret: bool = False) -> jax.Array:
     """x: [E, C, d]; w1/w3: [E, d, f]; w2: [E, f, d] -> [E, C, d] (x.dtype)."""
     E, C, d = x.shape
     f = w1.shape[2]
-    # largest divisor of f that fits the requested tile: a non-dividing
-    # block_f degrades to a smaller (still exact) tiling instead of failing
-    bf = min(block_f, f)
-    while f % bf:
-        bf -= 1
+    bf = f_tile(f, block_f)
     grid = (E, f // bf)
     out = pl.pallas_call(
         _kernel,

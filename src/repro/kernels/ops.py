@@ -1,26 +1,28 @@
 """Jit'd public wrappers over the Pallas kernels.
 
-On CPU (this container) the kernels run with interpret=True; on a real TPU
-set ``REPRO_PALLAS_INTERPRET=0`` (or pass interpret=False) to lower natively.
-``moe_ffn_pallas`` is the drop-in hot path for the capacity-dispatched MoE
-block (dispatch/combine stay in XLA; the grouped GEMMs run in the
-double-buffered kernel).
+Interpret mode is decided by the backend (``default_interpret``): the
+kernels run in the Pallas interpreter on the CPU backend only, and lower
+natively everywhere else — never interpreted on a TPU. ``expert_ffn_op`` is
+the drop-in hot path for the capacity-dispatched MoE block
+(dispatch/combine stay in XLA; the grouped GEMMs run in the double-buffered
+kernel).
 """
 from __future__ import annotations
 
-import os
+import jax
 
-from repro.kernels.expert_ffn import expert_ffn
+from repro.kernels.expert_ffn import DEFAULT_BLOCK_F, expert_ffn
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.flash_decode import flash_decode
 from repro.kernels.ssd_scan import ssd_scan
 
 
 def default_interpret() -> bool:
-    return os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
+    """Interpret Pallas kernels on the CPU backend only."""
+    return jax.default_backend() == "cpu"
 
 
-def expert_ffn_op(x, w1, w3, w2, *, block_f: int = 512,
+def expert_ffn_op(x, w1, w3, w2, *, block_f: int = DEFAULT_BLOCK_F,
                   interpret: bool | None = None):
     return expert_ffn(x, w1, w3, w2, block_f=block_f,
                       interpret=default_interpret() if interpret is None

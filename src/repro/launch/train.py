@@ -38,6 +38,7 @@ def main():
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from repro.configs.base import INPUT_SHAPES, get_config, reduced
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.data.pipeline import SyntheticLM
     from repro.launch import partition
     from repro.launch.mesh import make_production_mesh, mesh_info, n_chips
@@ -45,6 +46,7 @@ def main():
     from repro.training.optimizer import AdamW, AdamWState
     from repro.training.train_loop import make_train_step
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     shape = INPUT_SHAPES[args.shape]
     if args.reduced:

@@ -919,3 +919,22 @@ def build(cfg: ArchConfig, mesh_info=None) -> ModelBundle:
             and opt_flags.static_window()):
         return build_dense_pattern(cfg, mesh_info=mesh_info)
     return _BUILDERS[cfg.family](cfg, mesh_info=mesh_info)
+
+
+EXPERT_KEYS = ("w1", "w3", "w2")
+
+
+def init_without_experts(cfg: ArchConfig) -> Callable:
+    """A jitted ``key -> params``: ``build(cfg).init`` without the
+    routed-expert stacks, for the serving engines, which take those weights
+    from a host store (``core.cache.HostExpertStore``). XLA drops the dead
+    expert generation, so no expert-sized array is built on the device."""
+    bundle = build(cfg)
+
+    def init(k):
+        p = bundle.init(k)
+        p["layers"]["moe"] = {n: a for n, a in p["layers"]["moe"].items()
+                              if n not in EXPERT_KEYS}
+        return p
+
+    return jax.jit(init)

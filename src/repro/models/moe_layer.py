@@ -33,7 +33,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro import compat
 from repro.configs.base import ArchConfig
 from repro.models.layers import PDT
 
@@ -126,10 +125,9 @@ def _dispatch_compute_combine(x2d, w, ids, w1, w3, w2, *, capacity: int,
         jnp.where(oob[:, None], 0, x2d[stok]), mode="drop")
     if use_pallas:
         # grouped GEMMs via the double-buffered expert-streaming kernel
-        # (the paper's prefill pipeline, TPU-native; interpret=True on CPU)
+        # (the paper's prefill pipeline, TPU-native; interpreted on CPU)
         from repro.kernels.ops import expert_ffn_op
-        bf = min(512, w1.shape[2])
-        y = expert_ffn_op(buf, w1, w3, w2, block_f=bf)
+        y = expert_ffn_op(buf, w1, w3, w2)
     else:
         h = jnp.einsum("ecd,edf->ecf", buf, w1)
         h = jax.nn.silu(h) * jnp.einsum("ecd,edf->ecf", buf, w3)
@@ -255,7 +253,7 @@ def moe_ffn(x, p, cfg: ArchConfig, *, mesh_info=None, capacity_factor=None):
         return y.reshape(xl.shape), aux
 
     xspec = P(dp, *([None] * (x.ndim - 1)))
-    out = compat.shard_map(
+    out = jax.shard_map(
         body, mesh=mesh, in_specs=(xspec, wspec),
         out_specs=(xspec, P()), check_vma=False)(x, {k: p[k] for k in wspec})
     return out

@@ -332,7 +332,8 @@ class BatchedServingEngine(EngineCore):
                  fused_prefill: Optional[bool] = None,
                  stats=None, predictor=None, cache_capacity=None,
                  temperature: float = 0.0, sample_seed: int = 0,
-                 spans: Union[bool, SpanRecorder] = False):
+                 spans: Union[bool, SpanRecorder] = False,
+                 store=None):
         super().__init__(cfg, params, policy, stats=stats,
                          predictor=predictor, cache_capacity=cache_capacity,
                          temperature=temperature, sample_seed=sample_seed,
@@ -340,7 +341,8 @@ class BatchedServingEngine(EngineCore):
                          prefill_chunk=(prefill_budget
                                         if isinstance(prefill_budget, int)
                                         else None),
-                         fused_prefill=fused_prefill, spans=spans)
+                         fused_prefill=fused_prefill, spans=spans,
+                         store=store)
         # grouped_decode=True (default): the batched decode expert sweep is
         # segment-gathered — each distinct expert computes only its
         # selecting rows, one FFN launch per layer (bit-exact vs the dense

@@ -69,7 +69,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from typing import Dict, List, Optional, Sequence, Tuple, Union
+from typing import Callable, Dict, List, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -87,7 +87,7 @@ from repro.models import layers as L
 from repro.models import moe_layer as M
 from repro.models import opt_flags
 from repro.models.layers import PDT
-from repro.models.model import attn_dims
+from repro.models.model import EXPERT_KEYS, attn_dims
 from repro.obs.metrics import MetricsRegistry
 from repro.obs.spans import SpanRecorder
 from repro.serving.api import Event, SamplingParams, TokenEvent
@@ -249,7 +249,8 @@ class EngineCore:
                  temperature: float = 0.8, sample_seed: int = 0,
                  sched_batch: int = 1, prefill_chunk: Optional[int] = None,
                  fused_prefill: Optional[bool] = None,
-                 spans: Union[bool, SpanRecorder] = False):
+                 spans: Union[bool, SpanRecorder] = False,
+                 store: Optional[HostExpertStore] = None):
         assert cfg.is_moe and cfg.family in ("moe", "dense"), \
             "engine schedules experts; use bundle.decode for non-MoE archs"
         assert cfg.n_dense_layers == 0, "engine assumes uniform MoE stack"
@@ -260,10 +261,19 @@ class EngineCore:
         self.vp = L.vocab_pad_of(cfg.vocab)
 
         lp = params["layers"]
-        self.store = HostExpertStore.from_params(lp["moe"], self.L, self.E)
+        # routed experts live in host RAM: a caller-built store (shared by
+        # every engine of the process, its params then carry no expert
+        # stacks) or a host copy of the stacks in `params`
+        if store is None:
+            store = HostExpertStore.from_params(lp["moe"], self.L, self.E)
+        elif set(store.weights) != {(l, e) for l in range(self.L)
+                                    for e in range(self.E)}:
+            raise ValueError(f"expert store does not hold {self.L} layers "
+                             f"x {self.E} experts of {cfg.name}")
+        self.store = store
         # device-resident: everything except routed expert weights
         moe_dev = {k: v for k, v in lp["moe"].items()
-                   if k not in ("w1", "w3", "w2")}
+                   if k not in EXPERT_KEYS}
         self.dev = {
             "embed": params["embed"], "ln_f": params["ln_f"],
             "layers": {k: v for k, v in lp.items() if k != "moe"},
@@ -323,13 +333,20 @@ class EngineCore:
             stats=stats, predictor=predictor, state_constructor=sc,
             capacity=cap, batch=sched_batch, state=self.cache)
         assert self.sched.cache is self.cache, "ledger must be shared"
-        self._jit_fns()
+        for name, fn in self._jit_fns(cfg).items():
+            setattr(self, name, fn)
 
     # -- jitted per-layer kernels (compiled once; reused for every layer) ----
-    def _jit_fns(self):
-        cfg = self.cfg
+    @staticmethod
+    def _jit_fns(cfg: ArchConfig) -> Dict[str, Callable]:
+        """The engine's jitted per-layer kernels for ``cfg``, keyed by the
+        attribute each engine binds them to. A function of the config
+        alone, so they can also be lowered for a described chip without
+        building an engine (tests/test_tpu_compile.py)."""
         dims = attn_dims(cfg)
         eps = cfg.rms_eps
+        n_experts, top_k = cfg.n_experts, cfg.top_k
+        vp = L.vocab_pad_of(cfg.vocab)
 
         @jax.jit
         def attn_prefill(lp, x):
@@ -362,7 +379,7 @@ class EngineCore:
         def gate(moe_dev, lp, x):
             xn = L.rms_norm(x, lp["ln2"], eps)
             x2 = xn.reshape(-1, xn.shape[-1])
-            w, ids, probs = M.route(x2, moe_dev["router"], self.E, self.k)
+            w, ids, probs = M.route(x2, moe_dev["router"], n_experts, top_k)
             return xn, w, ids
 
         @jax.jit
@@ -412,21 +429,23 @@ class EngineCore:
 
         @jax.jit
         def head(p_lnf, embed, x_last):
-            x = L.rms_norm(x_last, p_lnf, self.cfg.rms_eps)
+            x = L.rms_norm(x_last, p_lnf, eps)
             lg = x @ embed.T.astype(x.dtype)
-            mask = jnp.arange(self.vp) < self.cfg.vocab
+            mask = jnp.arange(vp) < cfg.vocab
             return jnp.where(mask, lg.astype(jnp.float32), -1e9)
 
-        self._attn_prefill = attn_prefill
-        self._attn_prefill_chunk = attn_prefill_chunk
-        self._attn_decode = attn_decode
-        self._attn_decode_batched = attn_decode_batched
-        self._gate = gate
-        self._expert_raw = expert_raw
-        self._grouped_raw = grouped_raw
-        self._expert = expert_apply
-        self._shared = shared_apply
-        self._head = head
+        return {
+            "_attn_prefill": attn_prefill,
+            "_attn_prefill_chunk": attn_prefill_chunk,
+            "_attn_decode": attn_decode,
+            "_attn_decode_batched": attn_decode_batched,
+            "_gate": gate,
+            "_expert_raw": expert_raw,
+            "_grouped_raw": grouped_raw,
+            "_expert": expert_apply,
+            "_shared": shared_apply,
+            "_head": head,
+        }
 
     def _layer(self, l: int):
         return jax.tree.map(lambda a: a[l], self.dev["layers"])
@@ -525,15 +544,20 @@ class EngineCore:
         raw = self._grouped_ffn_raw(l, order, xn, disp.row_idx)  # [U, C, d]
         self.perf.inc("prefill_ffn_launches")
         self.perf.max_update("max_prefill_launches_per_layer", 1)
-        zeros = jnp.zeros((T, raw.shape[-1]), jnp.float32)
+        # scatter-back as one fixed-shape gather per expert: token t reads
+        # its row of the expert's group, or an appended zero row where it
+        # did not select the expert — the same bits as scattering the
+        # group into zeros, without a program per group size
+        U, C, d = raw.shape
+        flat = jnp.concatenate([raw.reshape(U * C, d),
+                                jnp.zeros((1, d), raw.dtype)])
+        src = np.full((len(order), T), U * C, np.int32)
+        for j in range(self.k):
+            src[disp.u_of[:, j], np.arange(T)] = (disp.u_of[:, j] * C
+                                                  + disp.c_of[:, j])
         for u, e in enumerate(order):
             gate_w = (w * (ids == e)).sum(-1).reshape(-1)
-            n = disp.counts[u]
-            if n:
-                rows = jnp.asarray(disp.row_idx[u, :n])
-                y = zeros.at[rows].set(raw[u, :n])
-            else:
-                y = zeros
+            y = flat[jnp.asarray(src[u])]
             acc = acc + (y * gate_w[:, None]).astype(acc.dtype)
         return acc.reshape(xn.shape)
 
@@ -830,10 +854,12 @@ class MoEServingEngine(EngineCore):
 
 
 def collect_traces(cfg: ArchConfig, params, prompts: Sequence[np.ndarray],
-                   max_new: int = 8) -> Tuple[ExpertsTracer, List[RequestResult]]:
+                   max_new: int = 8, *, store: Optional[HostExpertStore] = None
+                   ) -> Tuple[ExpertsTracer, List[RequestResult]]:
     """Offline preprocess (paper §IV-A): run an ODF-scheduled engine over a
-    small dataset slice and record per-token activation paths."""
-    engine = MoEServingEngine(cfg, params, policy="odf")
+    small dataset slice and record per-token activation paths. ``store``:
+    the host expert store the serving engine will share (see EngineCore)."""
+    engine = MoEServingEngine(cfg, params, policy="odf", store=store)
     tracer = ExpertsTracer(cfg.n_layers, cfg.n_experts, cfg.top_k)
     results = []
     for p in prompts:

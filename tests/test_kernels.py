@@ -80,8 +80,8 @@ def test_expert_ffn_from_pool_fused_prefill_parity():
 
 
 def test_expert_ffn_block_f_fallback():
-    """A block_f that does not divide d_expert degrades to the largest
-    dividing tile instead of asserting out (96 % 64 != 0 -> bf=48)."""
+    """A d_expert that fits one 128-lane block runs as a single full-dim
+    tile whatever block_f asks (96 with block_f=64 -> one 96 tile)."""
     E, C, d, f = 2, 8, 32, 96
     ks = jax.random.split(jax.random.PRNGKey(10), 4)
     x = jax.random.normal(ks[0], (E, C, d), jnp.float32)
@@ -92,6 +92,31 @@ def test_expert_ffn_block_f_fallback():
     want = ref.expert_ffn_ref(x, w1, w3, w2)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("f,block_f,want", [
+    (14336, 256, 256),    # mixtral: the VMEM-safe default tile
+    (14336, 512, 512),
+    (1408, 512, 128),     # only 128 of the lane multiples <= 512 divides
+    (96, 64, 96),         # fits one block
+    (384, 300, 128),      # a requested tile rounds down to lanes
+])
+def test_f_tile_picks_lane_multiples(f, block_f, want):
+    from repro.kernels.expert_ffn import f_tile
+    assert f_tile(f, block_f) == want
+
+
+def test_f_tile_refuses_unlayoutable_tile():
+    from repro.kernels.expert_ffn import f_tile
+    with pytest.raises(ValueError, match="multiple of 128"):
+        f_tile(1000, 512)
+
+
+def test_interpret_mode_follows_backend():
+    """Pallas kernels are interpreted on the CPU backend only; nothing in
+    the environment turns the interpreter on for an accelerator."""
+    from repro.kernels.ops import default_interpret
+    assert default_interpret() == (jax.default_backend() == "cpu")
 
 
 @pytest.mark.parametrize("B,H,Hkv,S,D,bq,bk", [

@@ -120,10 +120,10 @@ def test_pattern_builder_matches_window_semantics():
                                atol=6e-2)
 
 
-def test_moe_dispatch_pallas_kernel_parity(monkeypatch):
+def test_moe_dispatch_pallas_kernel_parity():
     """The Pallas expert_ffn kernel slot-in (REPRO_MOE_PALLAS) must match the
-    einsum dispatch path bit-for-tolerance on the same capacity buffers."""
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
+    einsum dispatch path bit-for-tolerance on the same capacity buffers
+    (interpreted: the CPU backend decides interpret mode)."""
     cfg = _moe_cfg(E=4, k=2, d=64, de=128)
     p = M.moe_params(jax.random.PRNGKey(0), cfg, n_model=1, dtype=jnp.float32)
     x = jax.random.normal(jax.random.PRNGKey(1), (12, cfg.d_model)) * 0.3
@@ -233,7 +233,6 @@ def test_grouped_ffn_pallas_backend_runs(monkeypatch, moe_serving_setup):
     kernel-grade (f32 accumulation — pinned by the interpret parity tests
     in test_kernels.py), so tokens are not compared bit-wise here."""
     monkeypatch.setenv("REPRO_OPT_GROUPED_FFN", "1")
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
     from repro.serving.batching import BatchedServingEngine
     cfg, params = moe_serving_setup
     eng = BatchedServingEngine(cfg, params, policy="duo", max_batch=2,
