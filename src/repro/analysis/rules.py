@@ -13,6 +13,8 @@ import ast
 import fnmatch
 from typing import Dict, Iterable, List, Optional, Sequence, Set, Tuple
 
+from repro.obs.spans import SPAN_NAMES
+
 from .lint import Finding, ModuleInfo, Rule, dotted_name, first_arg_src
 
 
@@ -137,11 +139,21 @@ MIGRATED_METRICS: Set[str] = {
 }
 # (b) Span lifecycle discipline: SpanRecorder mutators may be called only
 # at the declared request-lifecycle / engine-phase points below, so the
-# span taxonomy stays small enough to read as a timeline.  Read-only
-# recorder views (spans(), terminal_reasons(), ...) are fine anywhere.
+# span taxonomy stays small enough to read as a timeline, and every
+# interval opened (``begin``) is named by a literal in
+# ``repro.obs.spans.SPAN_NAMES``, the list trace readers select by.
+# Read-only recorder views (spans(), terminal_reasons(), ...) are fine
+# anywhere.
 SPAN_METHODS: Set[str] = {"begin", "end", "instant", "terminal"}
 SPAN_SCOPES: Dict[str, Tuple[str, ...]] = {
+    "core/cache.py": (
+        "ExpertResidency.prefetch",
+        "ExpertResidency.wait",
+    ),
     "serving/engine.py": (
+        "EngineCore.prefill_chunk",
+        "EngineCore.prefill_layers",
+        "EngineCore._prefill_moe",
         "MoEServingEngine.decode",
     ),
     "serving/batching.py": (
@@ -549,7 +561,8 @@ class ObsDisciplineRule(Rule):
         registry instruments — writes to the legacy attribute names (now
         read-only views) or to ``*.perf.<field>`` fork the bookkeeping;
     (b) ``SpanRecorder`` mutators (``*.obs.begin/end/instant/terminal``)
-        are called only at the lifecycle points declared in SPAN_SCOPES.
+        are called only at the lifecycle points declared in SPAN_SCOPES,
+        and ``begin`` names its span by a literal in SPAN_NAMES.
     """
 
     id = "obs-discipline"
@@ -617,17 +630,30 @@ class ObsDisciplineRule(Rule):
             if not (isinstance(recv, ast.Attribute) and recv.attr == "obs"):
                 continue
             scope = mod.scope(node)
-            if _scope_matches(scope, patterns):
-                continue
-            yield Finding(
-                rule=self.id, path=mod.relpath, line=node.lineno,
-                scope=scope, call=f"obs.{fn.attr}", arg=first_arg_src(node),
-                message=(
-                    f"span recorder `{fn.attr}()` outside the declared "
-                    "lifecycle scopes (rules.SPAN_SCOPES); spans open/close "
-                    "only at declared request-lifecycle / engine-phase points"
-                ),
-            )
+            if not _scope_matches(scope, patterns):
+                yield Finding(
+                    rule=self.id, path=mod.relpath, line=node.lineno,
+                    scope=scope, call=f"obs.{fn.attr}",
+                    arg=first_arg_src(node),
+                    message=(
+                        f"span recorder `{fn.attr}()` outside the declared "
+                        "lifecycle scopes (rules.SPAN_SCOPES); spans "
+                        "open/close only at declared request-lifecycle / "
+                        "engine-phase points"
+                    ),
+                )
+            elif fn.attr == "begin" and not (
+                    node.args and isinstance(node.args[0], ast.Constant)
+                    and node.args[0].value in SPAN_NAMES):
+                yield Finding(
+                    rule=self.id, path=mod.relpath, line=node.lineno,
+                    scope=scope, call="obs.begin", arg=first_arg_src(node),
+                    message=(
+                        "span opened under a name that is not a literal in "
+                        "repro.obs.spans.SPAN_NAMES; declare the name there "
+                        "so trace readers can select it"
+                    ),
+                )
 
 
 ALL_RULES: Tuple[Rule, ...] = (

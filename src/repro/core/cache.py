@@ -38,7 +38,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs.metrics import MetricsRegistry
+from repro.obs.spans import SpanRecorder
+
 ExpertKey = Tuple[int, int]  # (layer, expert)
+
+# The engine phase a residency operation belongs to (``CacheState.phase``).
+PHASES = ("prefill", "decode")
 
 
 class HostExpertStore:
@@ -116,6 +122,10 @@ class CacheState:
     while every resident entry is pinned — pinned must-have admissions may
     grow an all-pinned cache, speculative (unpinned) ones are declined, and
     unpinning shrinks an over-grown cache back to capacity.
+
+    Lookups are tallied once, by ``phase`` (one of ``PHASES``, set by the
+    engine as it enters a prefill layer or a decode step) and outcome;
+    ``hits``/``misses`` sum over the phases.
     """
 
     def __init__(self, capacity: int, bytes_per_expert: int):
@@ -125,8 +135,17 @@ class CacheState:
             collections.OrderedDict()  # key -> pinned
         self.events: List[CacheEvent] = []
         self.peak_resident = 0
-        self.hits = 0
-        self.misses = 0
+        self.phase = PHASES[0]
+        # (phase, hit) -> lookups
+        self.lookups: Dict[Tuple[str, bool], int] = collections.Counter()
+
+    @property
+    def hits(self) -> int:
+        return sum(n for (_, hit), n in self.lookups.items() if hit)
+
+    @property
+    def misses(self) -> int:
+        return sum(n for (_, hit), n in self.lookups.items() if not hit)
 
     # -- device-mirror hooks (overridden by ExpertResidency) -----------------
     def _on_admit(self, key: ExpertKey) -> None:
@@ -150,13 +169,12 @@ class CacheState:
         return sum(1 for k in keys if k in resident)
 
     def lookup(self, key: ExpertKey, t: float = 0.0) -> bool:
-        if key in self.resident:
-            self.hits += 1
+        hit = key in self.resident
+        self.lookups[self.phase, hit] += 1
+        if hit:
             self.touch(key)
             self.events.append(CacheEvent("hit", key, t))
-            return True
-        self.misses += 1
-        return False
+        return hit
 
     def admit(self, key: ExpertKey, t: float = 0.0, pinned: bool = True
               ) -> List[ExpertKey]:
@@ -293,9 +311,20 @@ class ExpertResidency(CacheState):
     engines size `capacity` so this never happens — asserted in
     tests/test_residency.py), the pool regrows rather than corrupting a
     live slot; `regrow_events` counts those.
+
+    Observability: each copy is an ``expert.fetch`` span and each barrier
+    an ``expert.wait`` span on ``obs`` (the engine's recorder), and
+    ``metrics`` (the engine's registry) counts by phase the copies
+    (``residency_fetches_total``), their bytes, all three slabs
+    (``residency_fetch_bytes_total``), the copies evicted before any
+    `slot` call read them (``residency_fetches_unused_total``) and the
+    lookups by outcome (``residency_lookups_total``). ``transfer_log``
+    keeps each copy's key and host time.
     """
 
-    def __init__(self, store: HostExpertStore, capacity: int):
+    def __init__(self, store: HostExpertStore, capacity: int, *,
+                 obs: Optional[SpanRecorder] = None,
+                 metrics: Optional[MetricsRegistry] = None):
         super().__init__(capacity, store.bytes_per_expert)
         self.store = store
         w1, w3, w2 = next(iter(store.weights.values()))
@@ -310,6 +339,27 @@ class ExpertResidency(CacheState):
         self._loaded: Set[ExpertKey] = set()
         self.transfer_log: List[Tuple[ExpertKey, float]] = []
         self.regrow_events = 0
+        self.obs = obs if obs is not None else SpanRecorder()
+        reg = metrics if metrics is not None else MetricsRegistry()
+        self._c_fetches = {p: reg.counter(
+            "residency_fetches_total", "expert copies host->HBM", phase=p)
+            for p in PHASES}
+        self._c_fetch_bytes = {p: reg.counter(
+            "residency_fetch_bytes_total",
+            "host->HBM bytes of expert copies (all three slabs)", phase=p)
+            for p in PHASES}
+        self._c_unused = {p: reg.counter(
+            "residency_fetches_unused_total",
+            "expert copies evicted before any launch read their slot",
+            phase=p) for p in PHASES}
+        for p in PHASES:
+            for hit, outcome in ((True, "hit"), (False, "miss")):
+                reg.gauge("residency_lookups_total",
+                          "expert-cache lookups by phase and outcome",
+                          fn=lambda k=(p, hit): self.lookups[k],
+                          phase=p, outcome=outcome)
+        # copied in, slot not yet read: key -> phase of the copy
+        self._unread: Dict[ExpertKey, str] = {}
 
     # -- ledger -> device mirroring -----------------------------------------
     def _on_admit(self, key: ExpertKey) -> None:
@@ -323,6 +373,9 @@ class ExpertResidency(CacheState):
         if slot is not None:
             self._free.append(slot)
             self._loaded.discard(key)
+        phase = self._unread.pop(key, None)
+        if phase is not None:
+            self._c_unused[phase].inc()
 
     def _regrow(self, new_pool_capacity: int) -> None:
         grown = new_pool_capacity - self.pool_capacity
@@ -351,6 +404,9 @@ class ExpertResidency(CacheState):
             return False
         if key in self._loaded:
             return True
+        phase = self.phase
+        span = self.obs.begin("expert.fetch", lane="prefetch", layer=key[0],
+                              expert=key[1], phase=phase)
         w1, w3, w2 = self.store.get(key)
         s = jnp.int32(slot)
         self._pools["w1"] = _pool_write(self._pools["w1"], s,
@@ -359,7 +415,11 @@ class ExpertResidency(CacheState):
                                         jax.device_put(w3))
         self._pools["w2"] = _pool_write(self._pools["w2"], s,
                                         jax.device_put(w2))
+        self.obs.end(span)
         self._loaded.add(key)
+        self._unread[key] = phase
+        self._c_fetches[phase].inc()
+        self._c_fetch_bytes[phase].inc(self.bytes_per_expert)
         self.transfer_log.append((key, time.perf_counter()))
         return False
 
@@ -372,6 +432,7 @@ class ExpertResidency(CacheState):
         if key not in self.slot_of:
             self.admit(key, time.perf_counter(), pinned=True)
         self.prefetch(key)
+        self._unread.pop(key, None)
         return self.slot_of[key]
 
     @property
@@ -390,8 +451,11 @@ class ExpertResidency(CacheState):
     def wait(self, key: ExpertKey) -> None:
         """Sync point: block until the expert's weights are on device."""
         self.slot(key)
+        span = self.obs.begin("expert.wait", lane="prefetch", layer=key[0],
+                              expert=key[1])
         for p in self._pools.values():
             p.block_until_ready()
+        self.obs.end(span)
 
     @property
     def device_bytes(self) -> int:

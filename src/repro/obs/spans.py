@@ -10,14 +10,27 @@ so the taxonomy stays small enough to read as a timeline:
                    cancelled / slo_shed / rejected), plus
                    handoff.snapshot / handoff.restore flow endpoints and
                    autopilot.shed / autopilot.preempt annotations
-  prefill lane   : prefill (monolithic) / prefill.chunk spans
-  decode lane    : decode.step spans, ffn.launch / kv.scatter instants
-  prefetch lane  : prefetch.correction spans, prefetch.dispatch instants
+  prefill lane   : prefill (monolithic) / prefill.chunk spans, and under
+                   them attention / moe.route / moe.combine / sample; a
+                   final kv.scatter instant
+  decode lane    : decode.step spans, and under them attention /
+                   moe.route / moe.combine / sample
+  prefetch lane  : expert.fetch / expert.wait spans (one expert's copy
+                   into its pool slot, and the barrier on it),
+                   prefetch.correction spans, prefetch.dispatch instants
+
+Every interval name the engine opens is in ``SPAN_NAMES``; the lint
+checks ``begin`` sites against it and trace readers select by it.
 
 Design constraints, in order:
 
   * **Off by default, cheap when off.** Every public method starts with
     an ``enabled`` check; disabled cost is one attribute load + branch.
+  * **On the profiler's clock.** When enabled, ``begin``/``end`` also
+    enter/exit a ``jax.profiler.TraceAnnotation`` of the span's name, so
+    a ``jax.profiler`` trace holds every interval span beside the device
+    events (instants are not mirrored). Outside a profiler session an
+    annotation records nothing.
   * **Bounded.** Closed spans live in a ``deque(maxlen=capacity)`` ring;
     open spans live in a separate dict keyed by the token ``begin``
     returned, so ring eviction structurally cannot orphan an open span.
@@ -46,6 +59,12 @@ monotonic = time.perf_counter
 
 # Lane taxonomy: maps to one Perfetto track-thread per replica-process.
 SPAN_LANES = ("lifecycle", "prefill", "decode", "prefetch")
+
+# Every interval span the engine opens. Parents first, then the leaves
+# that say what the host is doing inside a chunk or step.
+SPAN_NAMES = ("prefill", "prefill.chunk", "decode.step",
+              "prefetch.correction", "attention", "moe.route",
+              "moe.combine", "sample", "expert.fetch", "expert.wait")
 
 # Knuth multiplicative hash for deterministic rid sampling.
 _HASH_K = 2654435761
@@ -78,6 +97,7 @@ class SpanRecorder:
         self.replica = int(replica)
         self.closed: Deque[Span] = collections.deque(maxlen=self.capacity)
         self._open: Dict[int, Span] = {}
+        self._annotations: Dict[int, object] = {}  # token -> open annotation
         self._next_token = 0
         # rid -> terminal reason; bounded FIFO so a long-lived server
         # doesn't accumulate one entry per request forever.
@@ -105,6 +125,11 @@ class SpanRecorder:
             return None
         tok = self._next_token
         self._next_token += 1
+        # imported here, so that importing repro.obs does not import jax
+        from jax.profiler import TraceAnnotation
+        ann = TraceAnnotation(name)
+        ann.__enter__()
+        self._annotations[tok] = ann
         self._open[tok] = Span(name, lane, monotonic(), 0.0, rid, args)
         return tok
 
@@ -115,6 +140,7 @@ class SpanRecorder:
         if span is None:
             raise ValueError(f"span token {token} ended twice or never opened")
         span.t1 = monotonic()
+        self._annotations.pop(token).__exit__(None, None, None)
         if args:
             span.args.update(args)
         if len(self.closed) == self.capacity:
@@ -160,6 +186,9 @@ class SpanRecorder:
 
     def clear(self) -> None:
         self.closed.clear()
+        for ann in self._annotations.values():
+            ann.__exit__(None, None, None)
+        self._annotations.clear()
         self._open.clear()
         self._terminal.clear()
         self._terminal_order.clear()

@@ -1022,7 +1022,9 @@ class BatchedServingEngine(EngineCore):
             req.prefill_active = active
             self._c_prefilled.inc(S - n_hit)
             self._prefix_insert(req)
+            st = self.obs.begin("sample", lane="prefill", rid=req.rid)
             tok = self._sample_req(req, logits[0])
+            self.obs.end(st)
             self._emit_token(req, tok, time.perf_counter(), first=True)
             self.queue.admission.model.observe_prefill(S - n_hit,
                                                        req.t_first - t0)
@@ -1081,7 +1083,9 @@ class BatchedServingEngine(EngineCore):
             req.prefill_active = [sorted(s) for s in req.active_sets]
             req.active_sets = None
             self._prefix_insert(req)
+            st = self.obs.begin("sample", lane="prefill", rid=req.rid)
             tok = self._sample_req(req, logits[0])
+            self.obs.end(st)
             self._emit_token(req, tok, time.perf_counter(), first=True)
             self.prefilling.remove(req)
             self._finish_prefill(req)
@@ -1148,6 +1152,7 @@ class BatchedServingEngine(EngineCore):
         B = len(batch)
         t0 = time.perf_counter()
         dt = self.obs.begin("decode.step", lane="decode", batch=B)
+        self.cache.phase = "decode"
         idx = np.asarray([r.slot for r in batch], np.int32)
         toks = np.asarray([[r.tokens[-1]] for r in batch], np.int32)
         pos_np = np.asarray([r.pos for r in batch], np.int32)
@@ -1166,6 +1171,7 @@ class BatchedServingEngine(EngineCore):
         step_trace = np.zeros((B, self.L, self.k), np.int32)
         step_pred = np.full((B, self.L, self.k), -1, np.int32)
         for l in range(self.L):
+            at = self.obs.begin("attention", lane="decode", layer=l)
             lp = self._layer(l)
             ck = self._K[l][jidx]
             cv = self._V[l][jidx]
@@ -1173,12 +1179,14 @@ class BatchedServingEngine(EngineCore):
                                                   pos)
             self._K[l] = self._K[l].at[jidx].set(ck)
             self._V[l] = self._V[l].at[jidx].set(cv)
-            self.obs.instant("kv.scatter", lane="decode", layer=l, rows=B)
+            self.obs.end(at)
+            rt = self.obs.begin("moe.route", lane="decode", layer=l)
             xn, w, ids = self._gate(self._moe_dev(l), lp, x)
             ids_np = np.asarray(ids).reshape(B, self.k)
             step_trace[:, l] = ids_np
             selections = [list(map(int, ids_np[b])) for b in range(B)]
             plan = self.sched.decode_plan(l, selections)
+            self.obs.end(rt)
             # hits + misses together cover exactly the distinct selections
             union = plan.hits + plan.misses
             np_pred = plan.predicted[: self.k]
@@ -1197,6 +1205,7 @@ class BatchedServingEngine(EngineCore):
                 r.misses += len(set(selections[b]) & miss_set)
             self.perf.inc("decode_layers")
             self.perf.inc("decode_rows_dense", len(union) * B)
+            ct = self.obs.begin("moe.combine", lane="decode", layer=l)
             acc = self._shared(self._moe_dev(l), xn)
             if union and self.grouped_decode:
                 # segment-gathered sweep: ONE launch computes only each
@@ -1207,8 +1216,6 @@ class BatchedServingEngine(EngineCore):
                 disp = group_by_expert(ids_np, union, bucket_cap=B,
                                        u_bucket_cap=min(self.E, B * self.k))
                 raw_g = self._grouped_ffn_raw(l, union, xn, disp.row_idx)
-                self.obs.instant("ffn.launch", lane="decode", layer=l,
-                                 rows=disp.n_launched)
                 self.perf.inc("decode_ffn_launches")
                 self.perf.inc("decode_rows_grouped", disp.n_rows)
                 self.perf.inc("decode_rows_launched", disp.n_launched)
@@ -1227,8 +1234,6 @@ class BatchedServingEngine(EngineCore):
                     eslot = jnp.int32(self.cache.slot((l, e)))
                     raw[e] = self._expert_raw(xn, *self.cache.pools,
                                               eslot)  # f32 [B, d]
-                self.obs.instant("ffn.launch", lane="decode", layer=l,
-                                 rows=len(union) * B, launches=len(union))
                 self.perf.inc("decode_ffn_launches", len(union))
                 self.perf.inc("decode_rows_launched", len(union) * B)
                 stacked = jnp.stack([raw[e] for e in union])  # [U, B, d]
@@ -1240,6 +1245,7 @@ class BatchedServingEngine(EngineCore):
                     y = stacked[jnp.asarray(inv[ids_np[:, j]]), rows]
                     acc = acc + (y * w[:, j, None]).astype(acc.dtype)
             x = x + acc.reshape(x.shape)
+            self.obs.end(ct)
             # prediction stream: prefetch layer l+1's experts for the batch
             if plan.prefetch_next:
                 self.obs.instant("prefetch.dispatch", lane="prefetch",
@@ -1251,10 +1257,13 @@ class BatchedServingEngine(EngineCore):
         # begin_request) accumulates pinned (L-1, e) entries forever
         self.sched.end_layer(self.L - 1)
         logits = self._head(self.dev["ln_f"], self.dev["embed"], x[:, -1])
+        st = self.obs.begin("sample", lane="decode", batch=B)
         lg_np = np.asarray(logits, np.float64)
         t_tok = time.perf_counter()
+        sampled = [self._sample_req(r, lg_np[b]) for b, r in enumerate(batch)]
+        self.obs.end(st)
         for b, r in enumerate(batch):
-            self._emit_token(r, self._sample_req(r, lg_np[b]), t_tok)
+            self._emit_token(r, sampled[b], t_tok)
             r.trace.append(step_trace[b])
             r.pred.append(step_pred[b])
         self.queue.admission.model.observe_decode_step(t_tok - t0)

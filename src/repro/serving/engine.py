@@ -313,7 +313,8 @@ class EngineCore:
         cap = cache_capacity or max(
             default_capacity(policy, self.L, self.E, self.k,
                              batch=sched_batch), pin_bound)
-        self.cache = ExpertResidency(self.store, capacity=cap)
+        self.cache = ExpertResidency(self.store, capacity=cap, obs=self.obs,
+                                     metrics=self.metrics)
         # residency counts surface as PULL gauges — evaluated at snapshot
         # time off the one ledger, so the cache hot path stays untouched
         self.metrics.gauge("residency_hits", "expert-cache hits (lifetime)",
@@ -565,13 +566,18 @@ class EngineCore:
         """Shared per-layer MoE body of both prefill paths: gate, dispatch
         the policy's PrefillPlan, add the expert output, unpin the layer.
         Returns (x_out, per-token ids [T, k] np, sorted active experts)."""
+        self.cache.phase = "prefill"
+        rt = self.obs.begin("moe.route", lane="prefill", layer=l)
         xn, w, ids = self._gate(self._moe_dev(l), lp, x)
         ids_np = np.asarray(ids)  # sync: gate result needed by dispatcher
         act = sorted(set(int(e) for e in ids_np.ravel()))
         plan = self.sched.prefill_plan(l, act)
+        self.obs.end(rt)
+        ct = self.obs.begin("moe.combine", lane="prefill", layer=l)
         y = self._run_experts_prefill(l, xn, w, ids, plan,
                                       ids_np=ids_np.reshape(-1, self.k))
         x = x + y
+        self.obs.end(ct)
         self.sched.end_layer(l)
         return x, ids_np.reshape(-1, self.k), act
 
@@ -598,9 +604,11 @@ class EngineCore:
         active: List[List[int]] = []
         paths = np.zeros((C, self.L, self.k), np.int32)
         for l in range(self.L):
+            at = self.obs.begin("attention", lane="prefill", layer=l)
             lp = self._layer(l)
             x, kc[l], vc[l], sp = self._attn_prefill_chunk(
                 lp, x, kc[l], vc[l], sp, start_j)
+            self.obs.end(at)
             x, ids_np, act = self._prefill_moe(l, lp, x)
             paths[:, l] = ids_np
             active.append(act)
@@ -635,8 +643,10 @@ class EngineCore:
         active: List[List[int]] = []
         paths = np.zeros((S, self.L, self.k), np.int32)
         for l in range(self.L):
+            at = self.obs.begin("attention", lane="prefill", layer=l)
             lp = self._layer(l)
             x, k_, v_ = self._attn_prefill(lp, x)
+            self.obs.end(at)
             x, ids_np, act = self._prefill_moe(l, lp, x)
             paths[:, l] = ids_np
             kc.append(k_)
@@ -736,6 +746,7 @@ class MoEServingEngine(EngineCore):
         trace = np.zeros((max_new, self.L, self.k), np.int32)
         pred_trace = np.full((max_new, self.L, self.k), -1, np.int32)
         n_dec = 0
+        self.cache.phase = "decode"
         for t in range(max_new):
             st = self.obs.begin("decode.step", lane="decode", rid=rid)
             tok = jnp.asarray([[out[-1]]], jnp.int32)

@@ -371,6 +371,62 @@ def test_obs_allows_span_calls_in_declared_scopes(tmp_path):
     assert [f for f in rep.findings if f.rule == "obs-discipline"] == []
 
 
+def test_obs_allows_the_engine_span_sites(tmp_path):
+    """The leaf spans that name the host's time inside a chunk or step:
+    copies and barriers in the residency, attention, routing and combine
+    in both phases, sampling at the step boundary."""
+    rep = lint_snippet(tmp_path, "core/cache.py", """
+        class ExpertResidency:
+            def prefetch(self, key):
+                span = self.obs.begin("expert.fetch", lane="prefetch")
+                self.obs.end(span)
+
+            def wait(self, key):
+                span = self.obs.begin("expert.wait", lane="prefetch")
+                self.obs.end(span)
+    """)
+    rep2 = lint_snippet(tmp_path, "serving/engine.py", """
+        class EngineCore:
+            def prefill_chunk(self, chunk, start, kc, vc, sp):
+                at = self.obs.begin("attention", lane="prefill", layer=0)
+                self.obs.end(at)
+
+            def _prefill_moe(self, l, lp, x):
+                rt = self.obs.begin("moe.route", lane="prefill", layer=l)
+                self.obs.end(rt)
+                ct = self.obs.begin("moe.combine", lane="prefill", layer=l)
+                self.obs.end(ct)
+    """)
+    rep3 = lint_snippet(tmp_path, "serving/batching.py", """
+        class BatchedServingEngine:
+            def _decode_step(self, batch):
+                at = self.obs.begin("attention", lane="decode")
+                self.obs.end(at)
+                rt = self.obs.begin("moe.route", lane="decode")
+                self.obs.end(rt)
+                st = self.obs.begin("sample", lane="decode")
+                self.obs.end(st)
+
+            def _run_prefill_chunk(self, req, C):
+                st = self.obs.begin("sample", lane="prefill", rid=req.rid)
+                self.obs.end(st)
+    """)
+    for r in (rep, rep2, rep3):
+        assert [f for f in r.findings if f.rule == "obs-discipline"] == []
+
+
+@pytest.mark.parametrize("name", ['"expert.copy"', "name"])
+def test_obs_flags_a_span_name_outside_span_names(tmp_path, name):
+    rep = lint_snippet(tmp_path, "core/cache.py", f"""
+        class ExpertResidency:
+            def prefetch(self, key, name=None):
+                span = self.obs.begin({name}, lane="prefetch")
+                self.obs.end(span)
+    """)
+    f = next(f for f in rep.findings if f.rule == "obs-discipline")
+    assert f.call == "obs.begin" and "SPAN_NAMES" in f.message
+
+
 def test_obs_allows_registry_mutation_and_view_reads(tmp_path):
     rep = lint_snippet(tmp_path, "serving/cluster.py", """
         class ReplicaPool:

@@ -227,13 +227,54 @@ def test_ring_eviction_never_orphans_open_spans():
     rec = SpanRecorder(enabled=True, capacity=4)
     tok = rec.begin("decode.step", lane="decode")
     for i in range(10):
-        rec.instant("ffn.launch", lane="decode", layer=i)
+        rec.instant("prefetch.dispatch", lane="prefetch", layer=i)
     assert len(rec.spans()) == 4 and rec.n_dropped == 6
     assert [s.args["layer"] for s in rec.spans()] == [6, 7, 8, 9]
     # the open span survived the churn and still closes cleanly
     assert len(rec.open_spans()) == 1
     rec.end(tok)
     assert rec.spans()[-1].name == "decode.step" and not rec.open_spans()
+
+
+def _trace_events(logdir):
+    """name -> [(start_ns, end_ns)] of every event in the one
+    ``.xplane.pb`` under ``logdir``."""
+    from pathlib import Path
+    path = next(Path(logdir).rglob("*.xplane.pb"))
+    out = {}
+    for plane in jax.profiler.ProfileData.from_file(str(path)).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                out.setdefault(ev.name, []).append(
+                    (ev.start_ns, ev.start_ns + ev.duration_ns))
+    return out
+
+
+def test_enabled_spans_reach_a_profiler_trace(tmp_path):
+    """An enabled recorder's intervals appear in a ``jax.profiler`` trace
+    under their own names, also when they end out of order; instants and a
+    disabled recorder write nothing there."""
+    on, off = SpanRecorder(enabled=True), SpanRecorder(enabled=False)
+    jax.profiler.start_trace(str(tmp_path))
+    outer = on.begin("decode.step", lane="decode")
+    inner = on.begin("expert.fetch", lane="prefetch", layer=0)
+    hidden = off.begin("moe.route", lane="decode")
+    on.instant("prefetch.dispatch", lane="prefetch")
+    time.sleep(0.002)
+    on.end(outer)                       # before the span it encloses
+    time.sleep(0.002)
+    on.end(inner)
+    off.end(hidden)
+    left_open = on.begin("sample", lane="decode")
+    on.clear()                          # closes the open annotation
+    jax.profiler.stop_trace()
+    ev = _trace_events(tmp_path)
+    assert "moe.route" not in ev and "prefetch.dispatch" not in ev
+    (s0, e0), = ev["decode.step"]
+    (s1, e1), = ev["expert.fetch"]
+    assert s0 <= s1 < e0 < e1
+    assert e1 - e0 >= 1_500_000                  # the second sleep
+    assert len(ev["sample"]) == 1 and left_open is not None
 
 
 def test_terminal_twice_raises():

@@ -19,6 +19,7 @@ from repro.configs.base import get_config, reduced
 from repro.core.cache import CacheState, ExpertResidency, HostExpertStore
 from repro.core.tracer import ExpertsTracer
 from repro.models.model import build
+from repro.obs.metrics import MetricsRegistry
 from repro.serving.batching import BatchedServingEngine
 from repro.serving.engine import MoEServingEngine
 
@@ -241,3 +242,75 @@ def test_shared_state_construction():
     sim = make_scheduler("lfp", 2, 3, 1, store.bytes_per_expert)
     assert isinstance(sim.cache, CacheState)
     assert not isinstance(sim.cache, ExpertResidency)
+
+
+# ---------------------------------------------------------------------------
+# counters by phase
+# ---------------------------------------------------------------------------
+
+
+def _by_phase(reg, name):
+    return {dict(c.labels)["phase"]: c.value for c in reg.series(name)}
+
+
+def test_fetch_counters_by_phase_and_unused_fetches():
+    """A copy counts under the phase it was issued in, with all three
+    slabs' bytes; a copy evicted before any ``slot`` call read it counts as
+    unused, one that was read does not."""
+    reg = MetricsRegistry()
+    res = ExpertResidency(_tiny_store(), capacity=2, metrics=reg)
+    res.admit((0, 0), pinned=False)
+    res.prefetch((0, 0))
+    res.slot((0, 0))                            # read by a launch
+    res.phase = "decode"
+    res.admit((0, 1), pinned=False)
+    res.prefetch((0, 1))                        # never read
+    assert res.admit((0, 2), pinned=False) == [(0, 0)]
+    assert res.admit((1, 0), pinned=False) == [(0, 1)]
+    res.prefetch((1, 0))
+    fetches = _by_phase(reg, "residency_fetches_total")
+    assert fetches == {"prefill": 1, "decode": 2}
+    assert sum(fetches.values()) == len(res.transfer_log)
+    assert _by_phase(reg, "residency_fetch_bytes_total") == {
+        "prefill": res.bytes_per_expert, "decode": 2 * res.bytes_per_expert}
+    assert _by_phase(reg, "residency_fetches_unused_total") == {
+        "prefill": 0, "decode": 1}
+    # lookups by phase and outcome, and the totals they sum to
+    assert res.lookup((1, 0)) and not res.lookup((0, 0))
+    res.phase = "prefill"
+    assert res.lookup((0, 2))
+    snap = reg.snapshot()
+    assert snap['residency_lookups_total{outcome="hit",phase="decode"}'] == 1
+    assert snap['residency_lookups_total{outcome="miss",phase="decode"}'] \
+        == 1
+    assert snap['residency_lookups_total{outcome="hit",phase="prefill"}'] \
+        == 1
+    assert (res.hits, res.misses) == (2, 1)
+
+
+@pytest.mark.parametrize("budget", [None, 3])
+def test_engine_fetch_counters_match_the_transfer_log(setup, budget):
+    """In a served run every transfer-log entry is one counted fetch, and
+    the decode lookups cover the scheduler's gate-time decode tally."""
+    cfg, params, prompts, stats = setup
+    # one layer's experts fit, so decode steps copy experts in too
+    eng = BatchedServingEngine(cfg, params, policy="duo", stats=stats,
+                               max_batch=2, max_seq=32, temperature=0.0,
+                               prefill_budget=budget,
+                               cache_capacity=cfg.n_experts)
+    for p in prompts:
+        eng.submit(p, max_new=3)
+    eng.run_until_drained()
+    fetches = _by_phase(eng.metrics, "residency_fetches_total")
+    assert sum(fetches.values()) == len(eng.cache.transfer_log)
+    assert fetches["prefill"] > 0 and fetches["decode"] > 0
+    nbytes = _by_phase(eng.metrics, "residency_fetch_bytes_total")
+    assert nbytes == {p: n * eng.cache.bytes_per_expert
+                      for p, n in fetches.items()}
+    unused = _by_phase(eng.metrics, "residency_fetches_unused_total")
+    assert all(0 <= unused[p] <= fetches[p] for p in fetches)
+    look = {(dict(g.labels)["phase"], dict(g.labels)["outcome"]): g.value
+            for g in eng.metrics.series("residency_lookups_total")}
+    assert sum(look.values()) == eng.cache.hits + eng.cache.misses
+    assert look["decode", "hit"] >= eng.sched.decode_hits
+    assert look["decode", "miss"] >= eng.sched.decode_misses
