@@ -34,7 +34,8 @@ SYNC_HOT_SCOPES: Dict[str, Tuple[str, ...]] = {
         "EngineCore._prefill_moe",
         "EngineCore._sample",
         "MoEServingEngine.prefill",
-        "MoEServingEngine.prefill_chunk",
+        "EngineCore.prefill_chunk",
+        "EngineCore.prefill_chunks",
         "MoEServingEngine._prefill_layers_chunked",
         "MoEServingEngine.decode",
     ),
@@ -42,7 +43,7 @@ SYNC_HOT_SCOPES: Dict[str, Tuple[str, ...]] = {
         "BatchedServingEngine.step",
         "BatchedServingEngine._decode_step",
         "BatchedServingEngine._prefill_work",
-        "BatchedServingEngine._run_prefill_chunk",
+        "BatchedServingEngine._run_prefill_chunks",
         "BatchedServingEngine._admit_and_prefill",
         "BatchedServingEngine._sample_req",
         "BatchedServingEngine._emit_token",
@@ -85,7 +86,7 @@ RESIDENCY_WRITERS: Tuple[str, ...] = (
     "ExpertResidency.*",                     # the pools' owner (core/cache.py)
     "BatchedServingEngine.__init__",         # allocation
     "BatchedServingEngine._decode_step",     # per-step KV append
-    "BatchedServingEngine._run_prefill_chunk",
+    "BatchedServingEngine._run_prefill_chunks",
     "BatchedServingEngine._admit_and_prefill",
     "BatchedServingEngine.restore",          # snapshot handoff scatter
     "BatchedServingEngine._release_slot",    # slot_pos invalidation
@@ -117,7 +118,7 @@ JIT_MUTABLE_SELF: Set[str] = {
 REGISTERED_JIT_CALLEES: Set[str] = {
     "_attn_prefill", "_attn_prefill_chunk", "_attn_decode",
     "_attn_decode_batched", "_gate", "_expert_raw", "_grouped_raw",
-    "_expert", "_shared", "_head",
+    "_expert", "_shared", "_head", "_concat_rows", "_split_rows",
     "expert_ffn", "expert_ffn_from_pool", "_pool_write",
 }
 # Helpers whose results are *sanctioned* shape sources: power-of-two
@@ -151,7 +152,7 @@ SPAN_SCOPES: Dict[str, Tuple[str, ...]] = {
         "ExpertResidency.wait",
     ),
     "serving/engine.py": (
-        "EngineCore.prefill_chunk",
+        "EngineCore.prefill_chunks",         # one sweep, several requests
         "EngineCore.prefill_layers",
         "EngineCore._prefill_moe",
         "MoEServingEngine.decode",
@@ -159,7 +160,7 @@ SPAN_SCOPES: Dict[str, Tuple[str, ...]] = {
     "serving/batching.py": (
         "BatchedServingEngine.submit_request",
         "BatchedServingEngine._admit_and_prefill",
-        "BatchedServingEngine._run_prefill_chunk",
+        "BatchedServingEngine._run_prefill_chunks",   # prefill.chunk
         "BatchedServingEngine._decode_step",
         "BatchedServingEngine.cancel",
         "BatchedServingEngine._retire",

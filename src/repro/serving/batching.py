@@ -28,14 +28,17 @@ contributions, and TBT-ledger entry reclaimed within the same call.
   * Chunked, stall-free prefill (paper §III phase disparity): a long prompt
     no longer freezes in-flight decoders for its whole prefill. Admitted
     requests sit in state ``prefilling``; each iteration spends the step's
-    token budget on chunks through ``EngineCore.prefill_chunk``, so
+    token budget on chunks through ``EngineCore.prefill_chunks``, so
     inter-token gaps for decoders stay bounded by one chunk + one decode
     step instead of a full prefill. The budget is shared FAIRLY:
     ``prefill_fairness="rr"`` (default) rotates the per-step budget across
     ALL prefilling requests so one long prompt cannot starve later
     arrivals' TTFT; ``"srf"`` serves shortest-remaining-first (short
     prompts overtake long backlogs — best straggler TTFT, long prompts pay);
-    ``"fifo"`` restores the head-of-line discipline.
+    ``"fifo"`` restores the head-of-line discipline. A step's chunks share
+    one sweep through the layers: each request's attention runs on its own
+    KV, the MoE once over all the step's tokens, so each active expert is
+    copied once per step however many requests the budget spans.
     ``prefill_budget="auto"`` derives the budget each step from the live
     ``LatencyModel`` so one chunk + one batched decode step fits the
     TIGHTEST inter-token-gap target in flight (the engine ``tbt_slo`` and
@@ -70,7 +73,7 @@ from __future__ import annotations
 import collections
 import dataclasses
 import time
-from typing import Deque, Dict, List, Optional, Set, Union
+from typing import Deque, Dict, List, Optional, Set, Tuple, Union
 
 import jax.numpy as jnp
 import numpy as np
@@ -85,7 +88,8 @@ from repro.obs.spans import SpanRecorder, monotonic
 from repro.serving.api import (FinishEvent, GenerationRequest, RejectEvent,
                                RequestSnapshot, SamplingParams, StepEvents,
                                TokenEvent)
-from repro.serving.engine import EngineCore, RequestResult, group_by_expert
+from repro.serving.engine import (EngineCore, PrefillChunk, RequestResult,
+                                  group_by_expert)
 
 
 @dataclasses.dataclass
@@ -1044,35 +1048,45 @@ class BatchedServingEngine(EngineCore):
             req.state = "running"
             self.running.append(req)
 
-    def _run_prefill_chunk(self, req: Request, C: int) -> None:
-        """Advance one 'prefilling' request by a C-token chunk.
+    def _run_prefill_chunks(self, work: List[Tuple[Request, int]]) -> None:
+        """Advance each 'prefilling' request of ``work`` by its C-token
+        chunk, all of them in ONE sweep through the layers.
 
-        The chunk runs through `EngineCore.prefill_chunk` directly against
-        the request's KV slot: it attends over the slot's already-written
-        prefix and appends its own K/V, and the scheduler sees it through
-        the ordinary per-layer `prefill_plan` path. When the request's
-        final chunk completes, its first token is sampled — exactly the
-        token monolithic prefill would have produced — and it joins this
-        same step's decode batch (like a monolithically prefilled arrival).
+        The chunks run through `EngineCore.prefill_chunks` directly against
+        each request's KV carry: a chunk attends over its request's
+        already-written prefix and appends its own K/V, and the scheduler
+        sees the step's chunks together through one per-layer
+        `prefill_plan`, so each active expert is copied once per step, not
+        once per request. When a request's final chunk completes, its first
+        token is sampled — exactly the token monolithic prefill would have
+        produced — and it joins this same step's decode batch (like a
+        monolithically prefilled arrival).
         """
         t0 = time.perf_counter()
-        slot, start = req.slot, req.prefill_pos
-        stop = start + C
-        final = stop == req.prompt_len
-        pt = self.obs.begin("prefill.chunk", lane="prefill", rid=req.rid,
-                            start=start, tokens=C)
-        logits, req.pf_k, req.pf_v, req.pf_sp, act, _ = \
-            self.prefill_chunk(req.prompt[None, start:stop], start,
-                               req.pf_k, req.pf_v, req.pf_sp,
-                               need_logits=final)
-        for l in range(self.L):
-            req.active_sets[l].update(act[l])
-        req.prefill_pos = stop
-        self._c_prefilled.inc(C)
+        tokens = sum(C for _, C in work)
+        pt = self.obs.begin("prefill.chunk", lane="prefill",
+                            rids=[req.rid for req, _ in work], tokens=tokens)
+        chunks = []
+        for req, C in work:
+            start, stop = req.prefill_pos, req.prefill_pos + C
+            chunks.append(PrefillChunk(
+                req.prompt[None, start:stop], start, req.pf_k, req.pf_v,
+                req.pf_sp, need_logits=stop == req.prompt_len))
+        outs = self.prefill_chunks(chunks)
+        self._c_prefilled.inc(tokens)
         self.queue.admission.model.observe_prefill(
-            C, time.perf_counter() - t0)
-        if final:
+            tokens, time.perf_counter() - t0)
+        finals = []
+        for (req, C), c, (logits, act, _) in zip(work, chunks, outs):
+            req.pf_sp = c.sp   # its kc/vc lists were advanced in place
+            for l in range(self.L):
+                req.active_sets[l].update(act[l])
+            req.prefill_pos += C
+            if not c.need_logits:
+                continue
+            finals.append(req.rid)
             # one scatter into the slot pool for the whole prompt
+            slot = req.slot
             self.obs.instant("kv.scatter", lane="prefill", rid=req.rid,
                              rows=req.prompt_len)
             for l in range(self.L):
@@ -1089,7 +1103,7 @@ class BatchedServingEngine(EngineCore):
             self._emit_token(req, tok, time.perf_counter(), first=True)
             self.prefilling.remove(req)
             self._finish_prefill(req)
-        self.obs.end(pt, final=final)
+        self.obs.end(pt, finals=finals)
 
     def _prefill_work(self) -> int:
         """Spend up to this step's prefill budget advancing 'prefilling'
@@ -1108,7 +1122,8 @@ class BatchedServingEngine(EngineCore):
         modes). In every mode the budget goes to one request at a time
         (spilling to the next in order when it finishes early) rather than
         being split — chunk shapes stay constant, so the chunked-prefill
-        kernels compile once per budget, not once per (budget/n) share."""
+        kernels compile once per budget, not once per (budget/n) share. The
+        step's chunks then run as one sweep (`_run_prefill_chunks`)."""
         if not self.chunked:
             return 0  # monolithic mode: prefill happened at admission
         budget = self._current_budget()
@@ -1124,13 +1139,16 @@ class BatchedServingEngine(EngineCore):
                            key=lambda r: (r.prefill_remaining, r.rid))
         else:
             order = list(self.prefilling)  # fifo: head-of-line
+        work: List[Tuple[Request, int]] = []
         for req in order:
             if budget <= 0:
                 break
             C = min(budget, req.prefill_remaining)
-            self._run_prefill_chunk(req, C)
+            work.append((req, C))
             spent += C
             budget -= C
+        if work:
+            self._run_prefill_chunks(work)
         return spent
 
     def _sample_req(self, req: Request, logits_row) -> int:

@@ -13,6 +13,9 @@ can interleave host->device expert transfers with dispatched computation:
     the unit of work the stall-free continuous-batching front-end
     (``serving/batching.py``) interleaves with batched decode. Chunked and
     monolithic prefill are bit-identical at any chunk size.
+    ``prefill_chunks`` runs the chunks of several requests in one sweep:
+    attention per request, the MoE once per layer over all their tokens, so
+    each active expert is copied once per sweep rather than once per chunk.
   * decode: per layer — gate result compared against prefetched experts
     (sync point #1); misses corrected with a blocking fetch; the ExpertMLP is
     dispatched on the "prediction stream" (async) to choose layer l+1's
@@ -68,6 +71,7 @@ The module is split into:
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Callable, Dict, List, Optional, Sequence, Tuple, Union
 
@@ -95,7 +99,8 @@ from repro.serving.api import Event, SamplingParams, TokenEvent
 _PERF_FIELDS = ("decode_rows_dense", "decode_rows_grouped",
                 "decode_rows_launched", "decode_ffn_launches",
                 "decode_layers", "prefill_ffn_launches",
-                "prefill_moe_layers")
+                "prefill_moe_layers", "prefill_passes",
+                "prefill_pass_chunks")
 _PERF_MAX_FIELD = "max_prefill_launches_per_layer"
 
 
@@ -113,7 +118,10 @@ class PerfCounters:
     computed (grouped: after Cmax bucketing, padding included; dense:
     U * B). ``*_ffn_launches`` count expert-FFN kernel dispatches — the
     fused prefill path must keep prefill_ffn_launches == prefill_moe_layers
-    (exactly one launch per layer visit).
+    (exactly one launch per layer visit). ``prefill_passes`` counts chunked
+    prefill sweeps through the layers (``EngineCore.prefill_chunks`` calls)
+    and ``prefill_pass_chunks`` the request chunks they carried: their
+    ratio is how many requests share one expert sweep.
 
     Since the repro.obs migration this is a thin VIEW over the engine's
     :class:`MetricsRegistry`: every field reads a registry instrument
@@ -219,6 +227,21 @@ def group_by_expert(ids_np: np.ndarray, union: Sequence[int],
     return GroupedDispatch(row_idx=row_idx, counts=counts, u_of=u_of,
                            c_of=c_of, n_rows=sum(counts),
                            n_launched=int(row_idx.size))
+
+
+@dataclasses.dataclass
+class PrefillChunk:
+    """One request's share of a prefill sweep (``EngineCore.prefill_chunks``):
+    prompt tokens [1, C] at absolute positions start..start+C-1, and the
+    request's own per-layer KV carry buffers ``kc``/``vc`` ([1, W, Hkv, hd]
+    each) and slot positions ``sp`` [1, W], which the sweep advances in
+    place."""
+    tokens: np.ndarray
+    start: int
+    kc: List[jax.Array]
+    vc: List[jax.Array]
+    sp: jax.Array
+    need_logits: bool = True
 
 
 @dataclasses.dataclass
@@ -435,6 +458,18 @@ class EngineCore:
             mask = jnp.arange(vp) < cfg.vocab
             return jnp.where(mask, lg.astype(jnp.float32), -1e9)
 
+        @jax.jit
+        def concat_rows(*xs):
+            """The chunks of one prefill sweep, [1, C_i, d] each, side by
+            side as one [1, sum C_i, d] token block for the layer's MoE."""
+            return jnp.concatenate(xs, axis=1)
+
+        @functools.partial(jax.jit, static_argnums=1)
+        def split_rows(x, sizes):
+            """Inverse of concat_rows: the chunks' own rows back, one
+            program per tuple of chunk sizes."""
+            return tuple(jnp.split(x, np.cumsum(sizes)[:-1].tolist(), axis=1))
+
         return {
             "_attn_prefill": attn_prefill,
             "_attn_prefill_chunk": attn_prefill_chunk,
@@ -446,6 +481,8 @@ class EngineCore:
             "_expert": expert_apply,
             "_shared": shared_apply,
             "_head": head,
+            "_concat_rows": concat_rows,
+            "_split_rows": split_rows,
         }
 
     def _layer(self, l: int):
@@ -598,23 +635,55 @@ class EngineCore:
         need_logits=False — kc, vc, sp, active_per_layer for this chunk,
         per-token paths [C, L, k]).
         """
-        x = self.dev["embed"].at[jnp.asarray(chunk)].get(mode="clip")
-        C = chunk.shape[1]
-        start_j = jnp.int32(start)
-        active: List[List[int]] = []
-        paths = np.zeros((C, self.L, self.k), np.int32)
+        c = PrefillChunk(chunk, start, kc, vc, sp, need_logits)
+        (logits, active, paths), = self.prefill_chunks([c])
+        return logits, c.kc, c.vc, c.sp, active, paths
+
+    def prefill_chunks(self, chunks: Sequence[PrefillChunk]):
+        """Run the prefill chunks of several requests through all layers in
+        ONE sweep.
+
+        Per layer, each chunk's attention runs on its own KV exactly as a
+        lone ``prefill_chunk`` would run it; then the MoE runs once over
+        the chunks' tokens side by side: one gate, one ``prefill_plan``
+        over the union of their experts, one expert sweep that copies each
+        active expert at most once, one ``end_layer``. Each chunk gets its
+        own rows back. Every MoE op is row-wise and every plan orders the
+        experts the same way whatever else is active, so each request's
+        bits are those of its chunk run alone; a single chunk takes exactly
+        the launches of the one-request sweep.
+
+        Updates each chunk's kc/vc/sp in place and returns, per chunk,
+        (logits [1, Vp] of its last position or None, active_per_layer,
+        per-token paths [C, L, k]).
+        """
+        n = len(chunks)
+        sizes = tuple(int(c.tokens.shape[1]) for c in chunks)
+        bounds = np.cumsum((0,) + sizes)
+        self.perf.inc("prefill_passes")
+        self.perf.inc("prefill_pass_chunks", n)
+        xs = [self.dev["embed"].at[jnp.asarray(c.tokens)].get(mode="clip")
+              for c in chunks]
+        starts = [jnp.int32(c.start) for c in chunks]
+        active: List[List[List[int]]] = [[] for _ in chunks]
+        paths = [np.zeros((C, self.L, self.k), np.int32) for C in sizes]
         for l in range(self.L):
             at = self.obs.begin("attention", lane="prefill", layer=l)
             lp = self._layer(l)
-            x, kc[l], vc[l], sp = self._attn_prefill_chunk(
-                lp, x, kc[l], vc[l], sp, start_j)
+            for i, c in enumerate(chunks):
+                xs[i], c.kc[l], c.vc[l], c.sp = self._attn_prefill_chunk(
+                    lp, xs[i], c.kc[l], c.vc[l], c.sp, starts[i])
             self.obs.end(at)
-            x, ids_np, act = self._prefill_moe(l, lp, x)
-            paths[:, l] = ids_np
-            active.append(act)
-        logits = (self._head(self.dev["ln_f"], self.dev["embed"], x[:, -1])
-                  if need_logits else None)
-        return logits, kc, vc, sp, active, paths
+            x = xs[0] if n == 1 else self._concat_rows(*xs)
+            x, ids_np, _ = self._prefill_moe(l, lp, x)
+            xs = [x] if n == 1 else list(self._split_rows(x, sizes))
+            for i in range(n):
+                rows = ids_np[bounds[i]:bounds[i + 1]]
+                paths[i][:, l] = rows
+                active[i].append(sorted(set(int(e) for e in rows.ravel())))
+        return [((self._head(self.dev["ln_f"], self.dev["embed"], x[:, -1])
+                  if c.need_logits else None), active[i], paths[i])
+                for i, (c, x) in enumerate(zip(chunks, xs))]
 
     def prefill_layers(self, tokens: np.ndarray,
                        chunk_size: Optional[int] = None):

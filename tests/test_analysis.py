@@ -387,7 +387,7 @@ def test_obs_allows_the_engine_span_sites(tmp_path):
     """)
     rep2 = lint_snippet(tmp_path, "serving/engine.py", """
         class EngineCore:
-            def prefill_chunk(self, chunk, start, kc, vc, sp):
+            def prefill_chunks(self, chunks):
                 at = self.obs.begin("attention", lane="prefill", layer=0)
                 self.obs.end(at)
 
@@ -407,12 +407,42 @@ def test_obs_allows_the_engine_span_sites(tmp_path):
                 st = self.obs.begin("sample", lane="decode")
                 self.obs.end(st)
 
-            def _run_prefill_chunk(self, req, C):
+            def _run_prefill_chunks(self, work):
                 st = self.obs.begin("sample", lane="prefill", rid=req.rid)
                 self.obs.end(st)
     """)
     for r in (rep, rep2, rep3):
         assert [f for f in r.findings if f.rule == "obs-discipline"] == []
+
+
+def test_obs_prefill_sweep_span_sites(tmp_path):
+    """One prefill sweep carries several requests: the front end opens the
+    one ``prefill.chunk`` parent (with its ``rids``) around the engine's
+    multi-request sweep, which opens the per-layer leaves. The one-chunk
+    form delegates to the sweep and opens no span of its own."""
+    rep = lint_snippet(tmp_path, "serving/batching.py", """
+        class BatchedServingEngine:
+            def _run_prefill_chunks(self, work):
+                pt = self.obs.begin("prefill.chunk", lane="prefill",
+                                    rids=[r.rid for r, _ in work], tokens=8)
+                self.obs.end(pt)
+    """)
+    rep2 = lint_snippet(tmp_path, "serving/engine.py", """
+        class EngineCore:
+            def prefill_chunks(self, chunks):
+                at = self.obs.begin("attention", lane="prefill", layer=0)
+                self.obs.end(at)
+    """)
+    for r in (rep, rep2):
+        assert [f for f in r.findings if f.rule == "obs-discipline"] == []
+    rep3 = lint_snippet(tmp_path, "serving/engine.py", """
+        class EngineCore:
+            def prefill_chunk(self, chunk, start, kc, vc, sp):
+                at = self.obs.begin("attention", lane="prefill", layer=0)
+                self.obs.end(at)
+    """)
+    f = next(f for f in rep3.findings if f.rule == "obs-discipline")
+    assert f.scope == "EngineCore.prefill_chunk" and f.call == "obs.begin"
 
 
 @pytest.mark.parametrize("name", ['"expert.copy"', "name"])

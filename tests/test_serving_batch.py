@@ -261,6 +261,137 @@ def test_chunked_interleaving_is_stall_free(setup):
     assert long.prefill_active == refs[1].prefill_active
 
 
+def _count_sweeps(eng):
+    """Record how many request chunks each prefill sweep of ``eng`` carries
+    (and whether the multi-chunk row block was built)."""
+    seen, concat = [], []
+    sweep, cat = eng.prefill_chunks, eng._concat_rows
+
+    def spy_sweep(chunks):
+        seen.append(len(chunks))
+        return sweep(chunks)
+
+    def spy_cat(*xs):
+        concat.append(len(xs))
+        return cat(*xs)
+
+    eng.prefill_chunks, eng._concat_rows = spy_sweep, spy_cat
+    return seen, concat
+
+
+@pytest.mark.parametrize("fused", [False, True])
+@pytest.mark.parametrize("fairness", ["rr", "fifo"])
+def test_multi_request_sweep_bit_exact(setup, fairness, fused):
+    """A budget of 24 over prompts of 12, 16, 9 and 14 tokens makes steps
+    whose chunks span 2, then 3, then 1 request under both rr and fifo;
+    each step runs as one sweep, and every request still gets the
+    monolithic engine's tokens and per-layer active experts."""
+    cfg, params, prompts, refs = setup
+    eng = BatchedServingEngine(cfg, params, policy="duo", max_batch=4,
+                               max_seq=32, temperature=0.0,
+                               prefill_budget=24, prefill_fairness=fairness,
+                               fused_prefill=fused)
+    seen, concat = _count_sweeps(eng)
+    for p in prompts:
+        eng.submit(p, max_new=MAX_NEW)
+    finished = sorted(eng.run_until_drained(), key=lambda r: r.rid)
+    assert seen == [2, 3, 1]
+    assert concat == [2] * cfg.n_layers + [3] * cfg.n_layers
+    assert eng.perf.prefill_passes == 3
+    assert eng.perf.prefill_pass_chunks == 6
+    snap = eng.metrics.snapshot()
+    assert snap["engine_prefill_passes_total"] == 3
+    assert snap["engine_prefill_pass_chunks_total"] == 6
+    for i, r in enumerate(finished):
+        np.testing.assert_array_equal(r.result().tokens, refs[i].tokens,
+                                      err_msg=f"request {i} diverged")
+        assert r.prefill_active == refs[i].prefill_active
+
+
+def test_one_chunk_steps_count_one_pass_each(setup):
+    """With one prefilling request at a time every step carries one chunk:
+    passes equal chunks, and no row block is built."""
+    cfg, params, prompts, refs = setup
+    eng = BatchedServingEngine(cfg, params, policy="duo", max_batch=1,
+                               max_seq=32, temperature=0.0, prefill_budget=4)
+    seen, concat = _count_sweeps(eng)
+    for p in prompts[:2]:
+        eng.submit(p, max_new=MAX_NEW)
+    finished = sorted(eng.run_until_drained(), key=lambda r: r.rid)
+    n_chunks = sum(-(-len(p) // 4) for p in prompts[:2])
+    assert seen == [1] * n_chunks and concat == []
+    assert eng.perf.prefill_passes == eng.perf.prefill_pass_chunks == n_chunks
+    for i, r in enumerate(finished):
+        np.testing.assert_array_equal(r.result().tokens, refs[i].tokens)
+
+
+def test_one_chunk_step_matches_the_single_chunk_call(setup):
+    """A step with one chunk runs what the single-chunk call runs: the same
+    first token, active experts, expert-FFN launches and ledger events as
+    `prefill_layers` over `prefill_chunk` calls of the same size."""
+    cfg, params, prompts, _ = setup
+    p = prompts[1]
+    eng = BatchedServingEngine(cfg, params, policy="duo", max_batch=1,
+                               max_seq=32, temperature=0.0, prefill_budget=4)
+    req = eng.submit(p, max_new=MAX_NEW)
+    eng._admit_and_prefill(0.0)
+    while req.state == "prefilling":
+        eng._prefill_work()
+    one = MoEServingEngine(cfg, params, policy="duo", temperature=0.0,
+                           prefill_chunk=4)
+    logits, _, active, _ = one.prefill_layers(p.reshape(1, -1))
+    assert req.tokens == [int(np.asarray(logits)[0].argmax())]
+    assert req.prefill_active == active
+    for field in ("prefill_ffn_launches", "prefill_moe_layers",
+                  "prefill_passes", "prefill_pass_chunks"):
+        assert getattr(eng.perf, field) == getattr(one.perf, field), field
+    assert [(e.kind, e.key) for e in eng.cache.events] == \
+        [(e.kind, e.key) for e in one.cache.events]
+
+
+def test_two_chunk_sweep_plans_and_copies_once_per_layer(setup):
+    """One layer of a two-request sweep makes one `prefill_plan` call over
+    the union of the chunks' experts and copies each active expert at most
+    once; each chunk's logits, KV and routing equal its chunk run alone."""
+    from repro.models.layers import PDT
+    from repro.serving.engine import PrefillChunk
+    cfg, params, prompts, _ = setup
+    C, W = 8, 16
+    hkv, hd = cfg.n_kv_heads, cfg.hd
+
+    def fresh(tokens):
+        kc = [jax.numpy.zeros((1, W, hkv, hd), PDT)
+              for _ in range(cfg.n_layers)]
+        return PrefillChunk(tokens[None, :C], 0, kc, list(kc),
+                            jax.numpy.full((1, W), -1, jax.numpy.int32))
+
+    eng = MoEServingEngine(cfg, params, policy="duo", temperature=0.0,
+                           prefill_chunk=2 * C)
+    plans = []
+    plan = eng.sched.prefill_plan
+    eng.sched.prefill_plan = lambda l, act: (plans.append((l, list(act))),
+                                             plan(l, act))[1]
+    chunks = [fresh(prompts[0]), fresh(prompts[1])]
+    outs = eng.prefill_chunks(chunks)
+    assert [l for l, _ in plans] == list(range(cfg.n_layers))
+    for l, act in plans:
+        assert act == sorted(set(outs[0][1][l]) | set(outs[1][1][l]))
+        copied = [key for key, _ in eng.cache.transfer_log if key[0] == l]
+        assert len(copied) == len(set(copied))
+        assert {e for _, e in copied} <= set(act)
+    for c, (logits, active, paths) in zip(chunks, outs):
+        alone = MoEServingEngine(cfg, params, policy="duo", temperature=0.0,
+                                 prefill_chunk=2 * C)
+        ref = fresh(np.asarray(c.tokens[0]))
+        (lg, act1, paths1), = alone.prefill_chunks([ref])
+        np.testing.assert_array_equal(np.asarray(logits), np.asarray(lg))
+        np.testing.assert_array_equal(paths, paths1)
+        assert active == act1
+        for l in range(cfg.n_layers):
+            np.testing.assert_array_equal(np.asarray(c.kc[l]),
+                                          np.asarray(ref.kc[l]))
+
+
 def test_tbt_ledger_gaps():
     led = TBTLedger()
     led.observe(0, 1.0)
